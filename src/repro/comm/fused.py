@@ -6,9 +6,9 @@ update, one python round-trip per device. When the engine flushes a
 cohort together (all participants' uplinks, then all downlinks), the
 per-device tensors share a shape, so the whole direction collapses to a
 single (D, N) stacked buffer and ONE jitted, donated call into
-``repro.kernels.comm_fused`` (Pallas kernels or their jnp oracles,
-selected by the same REPRO_COMM_KERNEL backend logic as the sequential
-int8 path).
+``repro.kernels.comm_fused`` (compiled Pallas kernels on TPU, their jnp
+oracles on CPU, by the same ``repro.kernels.backend`` rule as the
+sequential int8 path).
 
 Compatibility contract with the sequential path (tested in
 tests/test_fused_comm.py):

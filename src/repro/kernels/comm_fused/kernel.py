@@ -20,8 +20,8 @@ each roundtrip into a single VMEM pass over a stacked cohort buffer:
 Top-k *selection* itself stays on ``jax.lax.top_k`` (XLA's native
 batched operator — sorting networks inside a Pallas TPU kernel are not
 supported); everything around it is fused here. The jnp oracles live in
-ref.py; ops.py picks kernel vs oracle with the same backend logic as
-kernels/int8_quant (REPRO_COMM_KERNEL / REPRO_PALLAS_INTERPRET).
+ref.py; ops.py picks kernel vs oracle by the repo-wide rule in
+``repro.kernels.backend`` (compiled kernel on TPU, oracle on CPU).
 """
 from __future__ import annotations
 
@@ -30,6 +30,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _QMAX = 127.0               # same symmetric affine range as int8_quant
 
@@ -78,37 +79,30 @@ def _sparse_combine_kernel(y_ref, mask_ref, scale_ref, out_ref, res_ref):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block_rows", "interpret"))
-def sparse_combine_pallas(y, mask, scale, *, block_rows: int = 64,
-                          interpret: bool = True):
+                   static_argnames=("block_rows", "block_cols", "interpret"))
+def sparse_combine_pallas(y, mask, scale, *, block_rows: int = 32,
+                          block_cols: int = 4096, interpret: bool = True):
     """y: (D, N) cohort buffer (already residual-added); mask: (D, N)
     0/1 survivor mask; scale: scalar (1.0 for top-k, n/k for unbiased
     rand-k). Returns (delivered, residual) = (y * mask * scale,
-    y - delivered) in one fused pass."""
+    y - delivered) in one fused pass. The grid tiles rows and columns,
+    so a block's VMEM footprint is bounded by (block_rows, block_cols)
+    whatever N is. D and N need not be multiples of the block: the
+    kernel is elementwise, and the out-of-range part of an edge block
+    is never stored."""
     d, n = y.shape
     br = min(block_rows, d)
-    nb = pl.cdiv(d, br)
-    pad = nb * br - d
-    if pad:
-        y = jnp.pad(y, ((0, pad), (0, 0)))
-        mask = jnp.pad(mask, ((0, pad), (0, 0)))
+    bc = min(block_cols, n)
     scale = jnp.asarray(scale, jnp.float32).reshape(1)
+    tile = pl.BlockSpec((br, bc), lambda i, j: (i, j))
     out, res = pl.pallas_call(
         _sparse_combine_kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((br, n), lambda i: (i, 0)),
-            pl.BlockSpec((br, n), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((br, n), lambda i: (i, 0)),
-            pl.BlockSpec((br, n), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb * br, n), y.dtype),
-            jax.ShapeDtypeStruct((nb * br, n), y.dtype),
-        ],
+        grid=(pl.cdiv(d, br), pl.cdiv(n, bc)),
+        in_specs=[tile, tile,
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=[tile, tile],
+        out_shape=[jax.ShapeDtypeStruct((d, n), y.dtype),
+                   jax.ShapeDtypeStruct((d, n), y.dtype)],
         interpret=interpret,
     )(y, mask, scale)
-    return out[:d], res[:d]
+    return out, res

@@ -7,10 +7,9 @@ update ``r' = (x + r) - decode(encode(x + r))`` — as ONE jitted call per
 cohort, donated on accelerator backends so the stacked input buffer is
 reused in place (donation is a no-op on CPU, where jax ignores it).
 
-Backend selection follows kernels/int8_quant/ops.py exactly
-(``kernel_enabled`` / ``interpret_mode``: real Pallas kernels on TPU or
-REPRO_COMM_KERNEL=1, the jnp oracles elsewhere), so one env var governs
-the sequential and the batched compression paths alike.
+Backend selection is the repo-wide rule (``repro.kernels.backend``):
+the Pallas kernels compiled on TPU, the jnp oracles on CPU, for the
+sequential and the batched compression paths alike.
 
 Numerics contract (tested): every wrapper is element-for-element the
 same math as the sequential per-device codec path in
@@ -28,8 +27,8 @@ from repro.kernels.comm_fused.kernel import (int8_roundtrip_pallas,
                                              sparse_combine_pallas)
 from repro.kernels.comm_fused.ref import (int8_roundtrip_ref,
                                           sparse_combine_ref)
-from repro.kernels.int8_quant.ops import (GROUP, interpret_mode,
-                                          kernel_enabled)
+from repro.kernels.backend import run_kernel
+from repro.kernels.int8_quant.ops import GROUP
 
 
 def _donate(*argnums):
@@ -63,16 +62,12 @@ def int8_group_geometry(n: int, group: int = GROUP):
 # --------------------------------------------------------------- int8
 @functools.lru_cache(maxsize=None)
 def _int8_fn(ef: bool, group: int):
-    use_k, interp = kernel_enabled(), interpret_mode()
-
     def rt(y):
         d, n = y.shape
-        rows = _as_group_rows(y, group)
-        if use_k:
-            dq = int8_roundtrip_pallas(rows, dtype=y.dtype,
-                                       interpret=interp)
-        else:
-            dq = int8_roundtrip_ref(rows, dtype=y.dtype)
+        dq = run_kernel(int8_roundtrip_pallas, _as_group_rows(y, group),
+                        dtype=y.dtype,
+                        cpu=functools.partial(int8_roundtrip_ref,
+                                              dtype=y.dtype))
         return dq.reshape(d, -1)[:, :n]
 
     if ef:
@@ -97,8 +92,6 @@ def fused_int8_roundtrip(x, r=None, group: int = GROUP):
 # -------------------------------------------------------- sparsifiers
 @functools.lru_cache(maxsize=None)
 def _sparse_fn(k: int, ef: bool, has_idx: bool):
-    use_k, interp = kernel_enabled(), interpret_mode()
-
     def rt(y, idx, scale):
         d, n = y.shape
         y32 = y.astype(jnp.float32)
@@ -108,11 +101,8 @@ def _sparse_fn(k: int, ef: bool, has_idx: bool):
             idx = jax.lax.top_k(jnp.abs(y32), k)[1]
         rows = jnp.arange(d)[:, None]
         mask = jnp.zeros((d, n), jnp.float32).at[rows, idx].set(1.0)
-        if use_k:
-            delivered, res = sparse_combine_pallas(y32, mask, scale,
-                                                   interpret=interp)
-        else:
-            delivered, res = sparse_combine_ref(y32, mask, scale)
+        delivered, res = run_kernel(sparse_combine_pallas, y32, mask,
+                                    scale, cpu=sparse_combine_ref)
         return delivered.astype(y.dtype), res
 
     if ef:

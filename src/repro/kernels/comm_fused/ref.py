@@ -1,8 +1,7 @@
 """Pure-jnp oracles for the fused cohort-compression kernels. Same math
 as kernel.py, no Pallas — the numerics tests assert the Pallas pair
-matches these, and the batched comm path falls back to them when the
-kernel path is disabled (the backend selection in
-kernels/int8_quant/ops.py: oracle everywhere but TPU by default)."""
+matches these, and the batched comm path runs them on CPU
+(``repro.kernels.backend``)."""
 from __future__ import annotations
 
 import jax.numpy as jnp

@@ -1,15 +1,11 @@
 """Jit'd public wrapper: model-layout adapter around the flash kernel.
 
-On CPU (this container) the kernel body runs under interpret=True; on a
-real TPU set REPRO_PALLAS_INTERPRET=0 to lower natively.
+Compiled on TPU, interpreted on CPU (``repro.kernels.backend``).
 """
 from __future__ import annotations
 
-import os
-
+from repro.kernels.backend import run_kernel
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
-
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
 
 
 def flash_attention(q, k, v, q_pos=None, k_pos=None, *, window: int = 0,
@@ -26,7 +22,7 @@ def flash_attention(q, k, v, q_pos=None, k_pos=None, *, window: int = 0,
     qf = q.transpose(0, 2, 1, 3).reshape(B * K * G, S, D)
     kf = k.transpose(0, 2, 1, 3).reshape(B * K, T, D)
     vf = v.transpose(0, 2, 1, 3).reshape(B * K, T, v.shape[-1])
-    out = flash_attention_fwd(qf, kf, vf, causal=causal, window=window,
-                              groups=G, interpret=INTERPRET)
+    out = run_kernel(flash_attention_fwd, qf, kf, vf, causal=causal,
+                     window=window, groups=G)
     out = out.reshape(B, K, G, S, -1).reshape(B, H, S, -1)
     return out.transpose(0, 2, 1, 3)

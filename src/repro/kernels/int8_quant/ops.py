@@ -4,58 +4,23 @@ scale/zp pair covers ``group`` values regardless of the tensor's last-dim
 width (CNN feature maps have as few as 16 channels — per-channel-row
 metadata would cost 50% of the wire).
 
-The Pallas pair and the jnp reference are numerically identical, so the
-default picks whichever is fast for the backend: the real kernel on TPU,
-the reference elsewhere (interpret-mode Pallas in the per-step training
-hot path would be the slowest option). REPRO_COMM_KERNEL=1/0 forces
-either path; REPRO_PALLAS_INTERPRET follows the repo-wide convention."""
+The Pallas pair and the jnp reference are numerically identical. The
+kernels run compiled on TPU; on CPU the reference runs, since the
+interpreter in the per-step training hot path would be the slowest
+option (``repro.kernels.backend``)."""
 from __future__ import annotations
 
+import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import run_kernel
 from repro.kernels.int8_quant.kernel import (int8_dequantize_pallas,
                                              int8_quantize_pallas)
 from repro.kernels.int8_quant.ref import (int8_dequantize_ref,
                                           int8_quantize_ref)
-
-_USE_KERNEL = None
-_INTERPRET = None
-
-
-def kernel_enabled() -> bool:
-    """True when the comm hot path should run the real Pallas kernels:
-    TPU by default, or forced either way via REPRO_COMM_KERNEL=1/0.
-    Shared by every comm kernel module (int8_quant, comm_fused) so one
-    env var governs the whole compression path."""
-    global _USE_KERNEL
-    if _USE_KERNEL is None:
-        env = os.environ.get("REPRO_COMM_KERNEL", "")
-        # lazy: jax.default_backend() initializes the backend
-        _USE_KERNEL = (env == "1" if env
-                       else jax.default_backend() == "tpu")
-    return _USE_KERNEL
-
-
-def interpret_mode() -> bool:
-    """Compiled Pallas on TPU, interpreter elsewhere (unless forced) —
-    otherwise default env vars would run interpret-mode Pallas in the
-    per-step training hot path on TPU, the slowest option."""
-    global _INTERPRET
-    if _INTERPRET is None:
-        env = os.environ.get("REPRO_PALLAS_INTERPRET", "")
-        _INTERPRET = (env == "1" if env
-                      else jax.default_backend() != "tpu")
-    return _INTERPRET
-
-
-# original (private) names, kept for existing callers
-_kernel_enabled = kernel_enabled
-_interpret = interpret_mode
-
 
 GROUP = 256                     # values per scale/zp pair (8 B / 256 B)
 
@@ -75,18 +40,24 @@ def int8_quantize(x, group: int = GROUP):
     """x: any-rank float array -> (q int8 (R,G), scale (R,1), zp (R,1),
     orig_shape). Rows are groups of ``group`` consecutive values (the
     tail group is zero-padded on the wire)."""
-    x2 = _as_groups(x, group)
-    if _kernel_enabled():
-        q, scale, zp = int8_quantize_pallas(x2, interpret=_interpret())
-    else:
-        q, scale, zp = int8_quantize_ref(x2)
+    q, scale, zp = _quantize(x, group)
     return q, scale, zp, x.shape
 
 
+# jitted, so the kernel-or-oracle choice follows the device the caller's
+# arrays are placed on, as in every other caller of run_kernel
+@functools.partial(jax.jit, static_argnums=1)
+def _quantize(x, group):
+    return run_kernel(int8_quantize_pallas, _as_groups(x, group),
+                      cpu=int8_quantize_ref)
+
+
 def int8_dequantize(q, scale, zp, shape, dtype=jnp.float32):
-    if _kernel_enabled():
-        x = int8_dequantize_pallas(q, scale, zp, dtype=dtype,
-                                   interpret=_interpret())
-    else:
-        x = int8_dequantize_ref(q, scale, zp, dtype=dtype)
+    return _dequantize(q, scale, zp, tuple(shape), jnp.dtype(dtype))
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _dequantize(q, scale, zp, shape, dtype):
+    x = run_kernel(int8_dequantize_pallas, q, scale, zp, dtype=dtype,
+                   cpu=functools.partial(int8_dequantize_ref, dtype=dtype))
     return x.reshape(-1)[:math.prod(shape)].reshape(shape)

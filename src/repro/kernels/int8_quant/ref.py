@@ -1,7 +1,7 @@
 """Pure-jnp oracle for the int8 affine quantize/dequantize kernels.
 Same math as kernel.py, no Pallas — the numerics tests assert the Pallas
-pair matches this reference, and the comm codec falls back to it when the
-kernel path is disabled."""
+pair matches this reference, and the comm codec runs it on CPU
+(``repro.kernels.backend``)."""
 from __future__ import annotations
 
 import jax.numpy as jnp

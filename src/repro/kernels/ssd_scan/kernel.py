@@ -1,6 +1,8 @@
 """Mamba2 SSD chunked-scan Pallas TPU kernel.
 
 Grid: (batch, heads, n_chunks) with the chunk axis innermost-sequential;
+blocks are head-major, (chunk, P) per step, so their last two dims meet
+the TPU's (8, 128)-or-whole-dimension tiling rule;
 the inter-chunk SSM state (P x N, f32) is carried in VMEM scratch across
 chunk steps (TPU grid iteration is sequential, so scratch persists).
 
@@ -23,7 +25,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, init_ref,
+def _ssd_kernel(x_ref, dtc_ref, csc_ref, csr_ref, b_ref, c_ref, init_ref,
                 y_ref, fs_ref, state_scr, *, l: int, nc: int):
     ci = pl.program_id(2)
 
@@ -31,40 +33,36 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, init_ref,
     def _init():
         state_scr[...] = init_ref[0, 0].astype(jnp.float32)
 
-    x = x_ref[0, :, 0].astype(jnp.float32)               # (l, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)             # (l,)
-    A = a_ref[0]                                         # scalar
+    x = x_ref[0, 0].astype(jnp.float32)                  # (l, P)
+    dt = dtc_ref[0, 0]                                   # (l, 1)
+    cs = csc_ref[0, 0]                                   # (l, 1)
+    cs_row = csr_ref[0, 0]                               # (1, l)
     B = b_ref[0].astype(jnp.float32)                     # (l, N)
     C = c_ref[0].astype(jnp.float32)                     # (l, N)
 
-    dA = dt * A                                          # (l,) <= 0
-    cs = jnp.cumsum(dA)                                  # (l,)
-
     # intra-chunk: L[i,j] = exp(cs_i - cs_j) for j <= i
-    seg = cs[:, None] - cs[None, :]
     tri = (jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
            >= jax.lax.broadcasted_iota(jnp.int32, (l, l), 1))
-    L = jnp.where(tri, jnp.exp(seg), 0.0)
+    L = jnp.where(tri, jnp.exp(cs - cs_row), 0.0)
     scores = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
     W = L * scores                                       # (l, l)
-    xdt = x * dt[:, None]                                # (l, P)
-    y = jax.lax.dot_general(W, xdt, (((1,), (0,)), ((), ())),
+    y = jax.lax.dot_general(W, x * dt, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
 
     # inter-chunk: y += exp(cs) * (C @ state^T)   state: (P, N)
     state = state_scr[...]
     y_off = jax.lax.dot_general(C, state, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-    y = y + y_off * jnp.exp(cs)[:, None]
-    y_ref[0, :, 0] = y.astype(y_ref.dtype)
+    y_ref[0, 0] = (y + y_off * jnp.exp(cs)).astype(y_ref.dtype)
 
     # state update: state = exp(sum dA) * state + sum_m tail_m dt_m x_m B_m
-    tail = jnp.exp(cs[-1] - cs) * dt                     # (l,)
-    upd = jax.lax.dot_general(x, B * tail[:, None],
+    last = cs_row[:, l - 1:]                             # (1, 1)
+    tail = jnp.exp(last - cs) * dt                       # (l, 1)
+    upd = jax.lax.dot_general(x, B * tail,
                               (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)  # (P, N)
-    state_scr[...] = state * jnp.exp(cs[-1]) + upd
+    state_scr[...] = state * jnp.exp(last) + upd
 
     @pl.when(ci == nc - 1)
     def _done():
@@ -72,10 +70,16 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, init_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan_pallas(x, dt, A, B, C, *, chunk: int = 128,
-                    initial_state=None, interpret: bool = True):
+def ssd_scan_pallas(x, dt, A, B, C, initial_state=None, *,
+                    chunk: int = 128, interpret: bool = True):
     """x: (b,s,h,p), dt: (b,s,h), A: (h,), B/C: (b,s,n).
-    Returns (y (b,s,h,p), final_state (b,h,p,n)). s % chunk == 0."""
+    Returns (y (b,s,h,p), final_state (b,h,p,n)). s % chunk == 0.
+
+    The kernel runs head-major: x and y move to (b,h,s,p) so each block's
+    last two dims are (chunk, p). The per-chunk cumulative decay
+    cs = cumsum(dt * A) is formed here and handed over as a column
+    (b,h,s,1) and a row (b,h,1,s), with dt as a column, so the kernel
+    needs no in-kernel transposes or scans."""
     b, s, h, p = x.shape
     n = B.shape[-1]
     assert s % chunk == 0, (s, chunk)
@@ -83,27 +87,31 @@ def ssd_scan_pallas(x, dt, A, B, C, *, chunk: int = 128,
     if initial_state is None:
         initial_state = jnp.zeros((b, h, p, n), x.dtype)
 
+    dt_h = jnp.swapaxes(dt, 1, 2).astype(jnp.float32)    # (b, h, s)
+    dA = dt_h * A.astype(jnp.float32)[None, :, None]
+    cs = jnp.cumsum(dA.reshape(b, h, nc, chunk), axis=-1).reshape(b, h, s)
+
     kernel = functools.partial(_ssd_kernel, l=chunk, nc=nc)
+    col = pl.BlockSpec((1, 1, chunk, 1), lambda bi, hi, ci: (bi, hi, ci, 0))
+    seq = pl.BlockSpec((1, 1, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0))
+    state = pl.BlockSpec((1, 1, p, n), lambda bi, hi, ci: (bi, hi, 0, 0))
     y, fs = pl.pallas_call(
         kernel,
         grid=(b, h, nc),
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda bi, hi, ci: (bi, ci, hi)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
+            seq, col, col,
+            pl.BlockSpec((1, 1, 1, chunk), lambda bi, hi, ci: (bi, hi, 0, ci)),
             pl.BlockSpec((1, chunk, n), lambda bi, hi, ci: (bi, ci, 0)),
             pl.BlockSpec((1, chunk, n), lambda bi, hi, ci: (bi, ci, 0)),
-            pl.BlockSpec((1, 1, p, n), lambda bi, hi, ci: (bi, hi, 0, 0)),
+            state,
         ],
-        out_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
-            pl.BlockSpec((1, 1, p, n), lambda bi, hi, ci: (bi, hi, 0, 0)),
-        ],
+        out_specs=[seq, state],
         out_shape=[
-            jax.ShapeDtypeStruct((b, s, h, p), x.dtype),
+            jax.ShapeDtypeStruct((b, h, s, p), x.dtype),
             jax.ShapeDtypeStruct((b, h, p, n), x.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A.astype(jnp.float32), B, C, initial_state)
-    return y, fs
+    )(jnp.swapaxes(x, 1, 2), dt_h[..., None], cs[..., None], cs[:, :, None],
+      B, C, initial_state)
+    return jnp.swapaxes(y, 1, 2), fs

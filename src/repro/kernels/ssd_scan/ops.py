@@ -1,13 +1,11 @@
-"""Jit'd public wrapper for the SSD scan kernel."""
+"""Jit'd public wrapper for the SSD scan kernel. Compiled on TPU,
+interpreted on CPU (``repro.kernels.backend``)."""
 from __future__ import annotations
 
-import os
-
+from repro.kernels.backend import run_kernel
 from repro.kernels.ssd_scan.kernel import ssd_scan_pallas
-
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
 
 
 def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, initial_state=None):
-    return ssd_scan_pallas(x, dt, A, B, C, chunk=chunk,
-                           initial_state=initial_state, interpret=INTERPRET)
+    return run_kernel(ssd_scan_pallas, x, dt, A, B, C, initial_state,
+                      chunk=chunk)

@@ -56,8 +56,6 @@ def _scan_corrected_cost(cfg, mesh, shape, step_kw):
             compiled = jax.jit(step, in_shardings=in_sh,
                                out_shardings=out_sh).lower(pa, ba).compile()
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):
-            cost = cost[0]
         pts.append((float(cost.get("flops", 0.0)),
                     float(cost.get("bytes accessed", 0.0)),
                     float(coll_fn(compiled.as_text())["_total"])))

@@ -243,6 +243,8 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None):
+    """Run one training job; returns the run summary printed at the end,
+    plus the per-round ``history``."""
     args = build_parser().parse_args(argv)
 
     cfg = get_config(args.arch)
@@ -391,7 +393,10 @@ def main(argv=None):
             json.dump({"history": eng.history, "final": final,
                        "clock": eng.clock, "comm": eng.comm,
                        "summary": summary}, f, indent=1)
+    return {**summary, "history": eng.history}
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

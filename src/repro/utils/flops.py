@@ -4,15 +4,19 @@ Eq.-1 inputs (|Wc|, q, Fc, Fs) for the simulator.
 
 Transformer costs are analytic (per sample of sequence length S);
 CNN unit costs come from XLA's own cost model (``compiled.cost_analysis``
-on a per-unit lowering), which is exact for convs.
+on a per-unit lowering for the host CPU, which is exact for convs),
+measured once and committed as ``cnn_unit_costs.json``.
 """
 from __future__ import annotations
 
 import functools
+import json
 import math
+import os
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
 
 from repro.models.api import SplitModel, get_subtree
 from repro.models.params import count_params
@@ -105,30 +109,52 @@ def head_flops(cfg, S: int) -> float:
     return 2.0 * S * cfg.d_model * cfg.vocab_padded
 
 
-@functools.lru_cache(maxsize=64)
-def _cnn_unit_costs(cfg) -> tuple:
-    """(fwd_flops, out_feature_elems) per unit via XLA cost analysis."""
+CNN_COST_TABLE = os.path.join(os.path.dirname(__file__),
+                              "cnn_unit_costs.json")
+
+
+def measure_cnn_unit_costs(cfg) -> tuple:
+    """(fwd_flops, out_feature_elems) per unit, per sample, from XLA's
+    cost analysis of each unit lowered for the host CPU. The CPU is named
+    explicitly: another backend's compiler counts other FLOPs for the
+    same convolution (the TPU's counts run 1.3-2.1x higher), and these
+    numbers drive the Eq.-1 clock and the split scheduler."""
     from repro.models.cnn import cnn_units
+    cpu = SingleDeviceSharding(jax.devices("cpu")[0])
     units, _ = cnn_units(cfg)
-    model = SplitModel(cfg)
-    params_abs = model.abstract()
+    params_abs = SplitModel(cfg).abstract()
     x = jax.ShapeDtypeStruct((1, cfg.image_size, cfg.image_size,
-                              cfg.in_channels), jnp.float32)
+                              cfg.in_channels), jnp.float32, sharding=cpu)
     out = []
-    for i, (defs_i, apply_i) in enumerate(units):
-        f = jax.jit(apply_i)
-        lowered = f.lower(jax.tree.map(
-            lambda d: jax.ShapeDtypeStruct(d.shape, jnp.float32),
-            params_abs["units"][i]), x)
-        cost = lowered.compile().cost_analysis()
-        if isinstance(cost, (list, tuple)):     # older jaxlib: per-device list
-            cost = cost[0] if cost else {}
-        flops = float(cost.get("flops", 0.0))
-        x = jax.eval_shape(apply_i, jax.tree.map(
-            lambda d: jax.ShapeDtypeStruct(d.shape, jnp.float32),
-            params_abs["units"][i]), x)
-        out.append((flops, float(math.prod(x.shape[1:]))))
+    for i, (_, apply_i) in enumerate(units):
+        p = jax.tree.map(
+            lambda d: jax.ShapeDtypeStruct(d.shape, jnp.float32,
+                                           sharding=cpu),
+            params_abs["units"][i])
+        cost = jax.jit(apply_i).lower(p, x).compile().cost_analysis()
+        y = jax.eval_shape(apply_i, p, x)
+        x = jax.ShapeDtypeStruct(y.shape, y.dtype, sharding=cpu)
+        out.append((float(cost.get("flops", 0.0)),
+                    float(math.prod(y.shape[1:]))))
     return tuple(out)
+
+
+@functools.cache
+def _cost_table() -> dict:
+    with open(CNN_COST_TABLE) as f:
+        return json.load(f)
+
+
+def _cnn_unit_costs(cfg) -> tuple:
+    """(fwd_flops, out_feature_elems) per unit, from the committed table
+    that ``measure_cnn_unit_costs`` generated, so the simulated clock is
+    the same on every backend. Regenerate it with
+    ``python -m repro.utils.flops``."""
+    from repro.configs import get_config
+    if cfg != get_config(cfg.name):
+        raise ValueError(f"{cfg.name}: no cost table for a modified CNN "
+                         "config; add it to cnn_unit_costs.json")
+    return tuple(tuple(u) for u in _cost_table()[cfg.name])
 
 
 # ---------------------------------------------------------------------------
@@ -178,3 +204,17 @@ def model_flops_6nd(cfg, n_tokens: int) -> float:
         active = total - routed + routed * cfg.top_k / cfg.n_experts
         return 6.0 * active * n_tokens
     return 6.0 * total * n_tokens
+
+
+def _write_cost_table():
+    from repro.configs import get_config, list_configs
+    table = {name: measure_cnn_unit_costs(get_config(name))
+             for name in list_configs()
+             if hasattr(get_config(name), "family")}
+    with open(CNN_COST_TABLE, "w") as f:
+        f.write("{\n" + ",\n".join(f"{json.dumps(k)}: {json.dumps(v)}"
+                                   for k, v in table.items()) + "\n}\n")
+
+
+if __name__ == "__main__":
+    _write_cost_table()

@@ -117,8 +117,6 @@ class Roofline:
 def analyze(compiled, *, arch: str, shape: str, n_chips: int,
             model_flops: float) -> Roofline:
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):
-        cost = cost[0]
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     coll = collective_bytes(compiled.as_text())

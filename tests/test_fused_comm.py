@@ -55,13 +55,19 @@ def test_int8_roundtrip_kernel_matches_ref_and_composed_pair(r, g):
                                atol=1e-6, rtol=1e-6)
 
 
-@pytest.mark.parametrize("d,n", [(1, 8), (5, 33), (130, 17)])
-def test_sparse_combine_kernel_matches_ref(d, n):
+@pytest.mark.parametrize(
+    "d,n,block_cols",
+    [(1, 8, 4096), (5, 33, 4096), (130, 17, 4096),
+     # several column tiles, a ragged last one in both axes
+     (5, 33, 8), (130, 17, 8)],
+    ids=["1-8", "5-33", "130-17", "5-33-cols8", "130-17-cols8"])
+def test_sparse_combine_kernel_matches_ref(d, n, block_cols):
     y = jax.random.normal(jax.random.fold_in(KEY, d * n), (d, n))
     mask = (jax.random.uniform(jax.random.fold_in(KEY, d + n), (d, n))
             < 0.3).astype(jnp.float32)
     for scale in (1.0, 4.0):
         out_k, res_k = sparse_combine_pallas(y, mask, scale,
+                                             block_cols=block_cols,
                                              interpret=True)
         out_r, res_r = sparse_combine_ref(y, mask, scale)
         np.testing.assert_array_equal(np.asarray(out_k),

@@ -6,6 +6,7 @@ locks at first jax init — see launch/dryrun.py), so here we:
   - lower the fused train step on a small in-process mesh via subprocess
   - unit-test the HLO collective parser on synthetic HLO text
 """
+import os
 import subprocess
 import sys
 import textwrap
@@ -95,12 +96,13 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from repro.configs import get_config, make_reduced
 from repro.core.round_step import make_s2fl_train_step, train_step_shardings
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import train_inputs
 from repro.models.transformer import abstract_model
 
 
 cfg = make_reduced(get_config("{arch}"))
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 step = make_s2fl_train_step(cfg, 1, 2, 0.01, dp_axes=("data",))
 batch = train_inputs(cfg, batch=8, seq=32)
 in_sh, out_sh = train_step_shardings(cfg, mesh, batch)
@@ -109,8 +111,6 @@ with mesh:
                 out_shardings=out_sh).lower(abstract_model(cfg),
                                             batch).compile()
 cost = c.cost_analysis()
-if isinstance(cost, (list, tuple)):   # older jaxlib: per-device list
-    cost = cost[0]
 assert cost["flops"] > 0
 print("OK", cost["flops"])
 """
@@ -127,6 +127,7 @@ def test_fused_step_lowers_on_small_mesh(arch):
              "HOME": "/root",
              # host-mesh lowering needs the CPU platform; skipping the
              # TPU probe also avoids a 60s metadata timeout on CI
-             "JAX_PLATFORMS": "cpu"}, cwd="/root/repo")
+             "JAX_PLATFORMS": "cpu"},
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     assert r.returncode == 0, r.stderr[-2000:]
     assert "OK" in r.stdout

@@ -15,9 +15,9 @@ from repro.data.partition import dirichlet_partition, federate
 from repro.data.synthetic import make_image_dataset, make_lm_dataset
 from repro.models import SplitModel
 from repro.optim import adam, clip_by_global_norm, cosine_schedule, sgd
-from repro.utils.flops import (client_portion_size, full_size,
-                               model_flops_6nd,
-                               split_costs)
+from repro.utils.flops import (_cnn_unit_costs, client_portion_size,
+                               full_size, measure_cnn_unit_costs,
+                               model_flops_6nd, split_costs)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -147,6 +147,37 @@ def test_split_costs_conserve_flops():
             np.testing.assert_allclose(c["fc"] + c["fs"], c["f_full"],
                                        rtol=1e-6)
             assert c["wc_size"] > 0 and c["feat_size"] > 0
+
+
+@pytest.mark.parametrize("arch", ["resnet8", "vgg16", "mobilenet"])
+def test_cnn_cost_table_matches_cpu_lowering(arch):
+    """The committed per-unit (flops, out_elems) table is exactly what
+    XLA's CPU cost analysis gives now: the Eq.-1 clock reads the table,
+    so it is the same on every backend, and this test says when the
+    table has to be regenerated (python -m repro.utils.flops)."""
+    cfg = get_config(arch)
+    assert _cnn_unit_costs(cfg) == measure_cnn_unit_costs(cfg)
+
+
+def test_cnn_cost_table_refuses_modified_config():
+    import dataclasses
+    cfg = dataclasses.replace(get_config("resnet8"), width_mult=2.0)
+    with pytest.raises(ValueError, match="no cost table"):
+        _cnn_unit_costs(cfg)
+
+
+@pytest.mark.parametrize("env", [None, "/some/shared/jax-cache"])
+def test_compile_cache_dir(monkeypatch, env):
+    """JAX_COMPILATION_CACHE_DIR wins when set; otherwise the fixed
+    directory inside the checkout, the same on every run."""
+    from repro.utils import compile_cache
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert compile_cache.cache_dir() == os.path.join(repo, ".jax_cache")
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+        assert compile_cache.cache_dir() == env
 
 
 def test_param_counts_match_assignment_scale():
