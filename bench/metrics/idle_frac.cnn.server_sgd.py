@@ -1,0 +1,7 @@
+"""Idle share of a CNN federation's traced window under the program's
+``PARTS["server_sgd"]`` spans; see ``_spans.py``."""
+from metrics._spans import idle_in
+
+
+def read(ctx):
+    return idle_in(ctx, "server_sgd")

@@ -55,6 +55,7 @@ import copy
 
 from repro.comm.codecs import Codec, get_codec
 from repro.comm.links import LatencySampler, StaticLink
+from repro.observe.spans import traced
 
 AUX_BYTES = 4.0          # the scalar aux-loss rider on each feature msg
 MESSAGES_PER_ROUND = 4   # dispatch, features up, grads down, collect
@@ -317,6 +318,7 @@ class CommChannel:
             rec.count(f"comm.{direction}.bytes", nbytes)
         return out, nbytes
 
+    @traced("s2fl.uplink")
     def uplink_features(self, cid, feats):
         """Device cid uploads its cut-layer features. Returns what the
         server receives (codec round-trip applied)."""
@@ -325,6 +327,7 @@ class CommChannel:
         self.up_bytes += nbytes
         return out
 
+    @traced("s2fl.downlink")
     def downlink_grads(self, cid, dfx):
         """Server returns the feature gradient to device cid."""
         out, nbytes = self._xfer(self.grad_codec, cid, dfx,
@@ -362,6 +365,7 @@ class CommChannel:
                 rec.count(f"comm.{direction}.bytes", nbytes)
         return out
 
+    @traced("s2fl.uplink")
     def uplink_features_cohort(self, pairs):
         """Batched ``uplink_features``: pairs = [(cid, feats)], returns
         what the server receives for each, in order."""
@@ -371,6 +375,7 @@ class CommChannel:
             self.up_bytes += nbytes
         return [out for out, _ in results]
 
+    @traced("s2fl.downlink")
     def downlink_grads_cohort(self, pairs):
         """Batched ``downlink_grads``: pairs = [(cid, dfx)]."""
         results = self._xfer_cohort(self.grad_codec, pairs,
@@ -380,6 +385,7 @@ class CommChannel:
         return [out for out, _ in results]
 
     # ------------------------------------------------------ model legs
+    @traced("s2fl.dispatch")
     def dispatch_leaves(self, cid, leaves):
         """Server -> device: the Wc dispatch leg (or the FedAvg model
         broadcast). Each leaf crosses the wire through the dispatch
@@ -389,12 +395,14 @@ class CommChannel:
         return self._model_leg(cid, leaves, "disp_down",
                                self._round_disp_down)
 
+    @traced("s2fl.collect")
     def collect_leaves(self, cid, leaves):
         """Device -> server: the updated-Wc collect leg (or the FedAvg
         QSGD-style update upload)."""
         return self._model_leg(cid, leaves, "disp_up",
                                self._round_disp_up)
 
+    @traced("s2fl.dispatch")
     def dispatch_leaves_cohort(self, pairs):
         """Batched Wc dispatch: pairs = [(cid, leaves)], one fused call
         for the whole cohort's client portions (leaves flattened in
@@ -402,6 +410,7 @@ class CommChannel:
         return self._model_leg_cohort(pairs, "disp_down",
                                       self._round_disp_down)
 
+    @traced("s2fl.collect")
     def collect_leaves_cohort(self, pairs):
         """Batched updated-Wc collect leg."""
         return self._model_leg_cohort(pairs, "disp_up",

@@ -115,6 +115,7 @@ from repro.core.simulation import (BYTES_PER_ELEM, CLIENT_FWD_FRAC,
                                    fedavg_round_comm_bytes,
                                    fedavg_round_time,
                                    fedavg_round_time_bytes)
+from repro.observe.spans import traced
 
 EXEC_MODES = ("sync", "semi_async")
 
@@ -766,6 +767,7 @@ class RoundDriver:
         self.staleness_cap = max(int(cap), max_age)
 
     # ------------------------------------------------------------- round
+    @traced("s2fl.driver")
     def run_round(self, participants, execute=None) -> RoundResult:
         """Drive one round. ``participants``: cids or Device objects.
 
@@ -976,8 +978,6 @@ class RoundDriver:
                                ("downlink", self._downlink)):
                 rec.gauge(f"{name}.live_flows", new_clock,
                           len(link._live))
-                rec.gauge(f"{name}.solves", new_clock, link.n_solves)
-                rec.gauge(f"{name}.retired", new_clock, link.n_retired)
                 if link.contended and new_clock > clock0:
                     rec.gauge(f"{name}.utilization", new_clock,
                               link.utilization(clock0, new_clock))

@@ -33,6 +33,7 @@ from repro.core.driver import FedAvgCost, MeteredCost, RoundDriver
 from repro.core.scheduler import FixedSplitScheduler, SlidingSplitScheduler
 from repro.core.split import SplitPlan, default_plan
 from repro.models.api import SplitModel
+from repro.observe.spans import round_span, span, traced
 from repro.optim import sgd
 from repro.utils import flops as flops_util
 
@@ -239,8 +240,9 @@ class S2FLEngine:
         d = self.data[self._shard_key(cid)]
         n = len(d["y"] if "y" in d else d["labels"])
         b = self._batch_size_of(cid)
-        idx = self.rng.choice(n, size=min(b, n), replace=n < b)
-        return {k: jnp.asarray(v[idx]) for k, v in d.items()}
+        with span("s2fl.batch", cid=cid):
+            idx = self.rng.choice(n, size=min(b, n), replace=n < b)
+            return {k: jnp.asarray(v[idx]) for k, v in d.items()}
 
     def _data_size(self, cid):
         d = self.data[self._shard_key(cid)]
@@ -327,8 +329,11 @@ class S2FLEngine:
     def _get_client_fwd(self, split):
         if split not in self._client_fwd:
             m = self.model
-            self._client_fwd[split] = jax.jit(
-                lambda p, b: m.client_forward(p, b, split))
+
+            def client_fwd(p, b):
+                return m.client_forward(p, b, split)
+
+            self._client_fwd[split] = jax.jit(client_fwd)
         return self._client_fwd[split]
 
     def _get_server_step(self, splits):
@@ -348,12 +353,12 @@ class S2FLEngine:
                 # and measurably slows S²FL (EXPERIMENTS §Accuracy).
                 return jnp.sum(jnp.stack(losses))
 
-            def step(sp, feats_list, batches):
+            def server_step(sp, feats_list, batches):
                 val, grads = jax.value_and_grad(loss_fn, argnums=(0, 1))(
                     sp, feats_list, batches)
                 return val, grads[0], grads[1]
 
-            self._server_step[splits] = jax.jit(step)
+            self._server_step[splits] = jax.jit(server_step)
         return self._server_step[splits]
 
     def _get_multi_server_step(self, gsplits):
@@ -386,7 +391,10 @@ class S2FLEngine:
                                   ).astype(w.dtype), sp, grads[0])
                 return new_sp, val, grads[1]
 
-            self._server_step[key] = jax.jit(jax.vmap(one),
+            def server_step_multi(sp_stack, feats_stacks, batches_stacks):
+                return jax.vmap(one)(sp_stack, feats_stacks, batches_stacks)
+
+            self._server_step[key] = jax.jit(server_step_multi,
                                              donate_argnums=_donate(0))
         return self._server_step[key]
 
@@ -396,7 +404,7 @@ class S2FLEngine:
             m = self.model
             lr = self.ecfg.lr
 
-            def upd(p, batch, dfx):
+            def client_update(p, batch, dfx):
                 _, vjp = jax.vjp(lambda pp: m.client_forward(pp, batch,
                                                              split), p)
                 (g,) = vjp(dfx)
@@ -404,7 +412,7 @@ class S2FLEngine:
                     lambda w, gw: (w - lr * gw.astype(w.dtype)
                                    ).astype(w.dtype), p, g)
 
-            self._client_upd[split] = jax.jit(upd)
+            self._client_upd[split] = jax.jit(client_update)
         return self._client_upd[split]
 
     # ------------------------------------------------- fused local step
@@ -428,8 +436,9 @@ class S2FLEngine:
         fwd = {}
         for gi, group in enumerate(groups):
             for c, b in zip(group, batches_by_g[gi]):
-                fwd[c] = self._get_client_fwd(splits[c])(
-                    client_params[c], b)
+                with span("s2fl.client_fwd", cid=c, split=splits[c]):
+                    fwd[c] = self._get_client_fwd(splits[c])(
+                        client_params[c], b)
         # 2. step 4 — the whole cohort's features cross the uplink at
         # once (one fused call; bytes metered per device, bit-equal)
         if ecfg.fused_comm:
@@ -446,13 +455,15 @@ class S2FLEngine:
 
         def seq_step(gi, group):
             gsplits = tuple(splits[c] for c in group)
-            loss, sgrads, dfxs = self._get_server_step(gsplits)(
-                server_copies[gi], feats_by_g[gi], batches_by_g[gi])
-            server_copies[gi] = jax.tree.map(
-                lambda w, g: (w - ecfg.lr * g.astype(w.dtype)
-                              ).astype(w.dtype), server_copies[gi],
-                sgrads)
-            losses[gi], dfx_by_g[gi] = float(loss), dfxs
+            with span("s2fl.server_step", group=gi, size=len(group)):
+                loss, sgrads, dfxs = self._get_server_step(gsplits)(
+                    server_copies[gi], feats_by_g[gi], batches_by_g[gi])
+                losses[gi], dfx_by_g[gi] = float(loss), dfxs
+            with span("s2fl.server_sgd", group=gi):
+                server_copies[gi] = jax.tree.map(
+                    lambda w, g: (w - ecfg.lr * g.astype(w.dtype)
+                                  ).astype(w.dtype), server_copies[gi],
+                    sgrads)
 
         if ecfg.fused_server:
             buckets = {}
@@ -467,15 +478,17 @@ class S2FLEngine:
                 if len(gis) == 1:          # nothing to batch with
                     seq_step(gis[0], groups[gis[0]])
                     continue
-                new_sp, vals, dfx_stack = self._get_multi_server_step(
-                    gsplits)(
-                    _tree_stack([server_copies[gi] for gi in gis]),
-                    _tree_stack([feats_by_g[gi] for gi in gis]),
-                    _tree_stack([batches_by_g[gi] for gi in gis]))
-                for j, gi in enumerate(gis):
-                    server_copies[gi] = _tree_index(new_sp, j)
-                    dfx_by_g[gi] = _tree_index(dfx_stack, j)
-                    losses[gi] = float(vals[j])
+                with span("s2fl.server_step", groups=len(gis),
+                          size=len(gsplits)):
+                    new_sp, vals, dfx_stack = self._get_multi_server_step(
+                        gsplits)(
+                        _tree_stack([server_copies[gi] for gi in gis]),
+                        _tree_stack([feats_by_g[gi] for gi in gis]),
+                        _tree_stack([batches_by_g[gi] for gi in gis]))
+                    for j, gi in enumerate(gis):
+                        server_copies[gi] = _tree_index(new_sp, j)
+                        dfx_by_g[gi] = _tree_index(dfx_stack, j)
+                        losses[gi] = float(vals[j])
         else:
             for gi, group in enumerate(groups):
                 seq_step(gi, groups[gi])
@@ -492,31 +505,34 @@ class S2FLEngine:
                         for gi, group in enumerate(groups)]
         for gi, group in enumerate(groups):
             for c, b, dfx in zip(group, batches_by_g[gi], dfx_by_g[gi]):
-                client_params[c] = self._get_client_update(splits[c])(
-                    client_params[c], b, dfx)
+                with span("s2fl.client_update", cid=c, split=splits[c]):
+                    client_params[c] = self._get_client_update(splits[c])(
+                        client_params[c], b, dfx)
         return losses
 
     # ------------------------------------------------------------- rounds
     def run_round(self):
         ecfg = self.ecfg
-        if self.fleet is not None:
-            # seeded fleet draw — churn/diurnal availability applied
-            # inside sample_cohort, dead devices never selected
-            participants = [int(c) for c in self.fleet.sample_cohort(
-                self.driver.round, ecfg.clients_per_round)]
-        else:
-            participants = list(self.rng.choice(
-                sorted(self.data), size=min(ecfg.clients_per_round,
-                                            len(self.data)),
-                replace=False))
-        if ecfg.mode == "fedavg":
-            return self._fedavg_round(participants)
-        return self._sfl_round(participants)
+        with round_span(self.driver.round):
+            if self.fleet is not None:
+                # seeded fleet draw — churn/diurnal availability applied
+                # inside sample_cohort, dead devices never selected
+                participants = [int(c) for c in self.fleet.sample_cohort(
+                    self.driver.round, ecfg.clients_per_round)]
+            else:
+                participants = list(self.rng.choice(
+                    sorted(self.data), size=min(ecfg.clients_per_round,
+                                                len(self.data)),
+                    replace=False))
+            if ecfg.mode == "fedavg":
+                return self._fedavg_round(participants)
+            return self._sfl_round(participants)
 
     def _sfl_round(self, participants):
         ecfg = self.ecfg
         group_losses = []              # last local step's per-group losses
 
+        @traced("s2fl.execute")
         def execute(splits):
             # the driver filters fault-killed devices from the cohort
             # before selection, so the alive list is exactly splits'
@@ -526,9 +542,10 @@ class S2FLEngine:
             if not alive:
                 groups = []
             elif ecfg.mode == "s2fl" and ecfg.use_balance:
-                groups = greedy_groups(
-                    [self._hists[self._shard_key(c)] for c in alive],
-                    ecfg.group_size)
+                with span("s2fl.group", devices=len(alive)):
+                    groups = greedy_groups(
+                        [self._hists[self._shard_key(c)] for c in alive],
+                        ecfg.group_size)
                 groups = [tuple(alive[i] for i in g) for g in groups]
             else:
                 groups = [(c,) for c in alive]
@@ -558,25 +575,33 @@ class S2FLEngine:
                     batches = [self._sample_batch(c) for c in group]
                     # Step 4: features cross the uplink (codec
                     # round-trip applied, exact wire bytes metered)
-                    feats = [self.channel.uplink_features(
-                        c, self._get_client_fwd(splits[c])(
-                            client_params[c], b))
-                        for c, b in zip(group, batches)]
+                    feats = []
+                    for c, b in zip(group, batches):
+                        with span("s2fl.client_fwd", cid=c,
+                                  split=splits[c]):
+                            fx = self._get_client_fwd(splits[c])(
+                                client_params[c], b)
+                        feats.append(self.channel.uplink_features(c, fx))
                     gsplits = tuple(splits[c] for c in group)
-                    loss, sgrads, dfxs = self._get_server_step(gsplits)(
-                        server_copies[gi], feats, batches)
-                    if step_i == ecfg.local_steps - 1:
-                        group_losses.append(float(loss))
+                    with span("s2fl.server_step", group=gi,
+                              size=len(group)):
+                        loss, sgrads, dfxs = self._get_server_step(
+                            gsplits)(server_copies[gi], feats, batches)
+                        if step_i == ecfg.local_steps - 1:
+                            group_losses.append(float(loss))
                     # W_s update (Eq. 4)
-                    server_copies[gi] = jax.tree.map(
-                        lambda w, g: (w - ecfg.lr * g.astype(w.dtype)
-                                      ).astype(w.dtype),
-                        server_copies[gi], sgrads)
+                    with span("s2fl.server_sgd", group=gi):
+                        server_copies[gi] = jax.tree.map(
+                            lambda w, g: (w - ecfg.lr * g.astype(w.dtype)
+                                          ).astype(w.dtype),
+                            server_copies[gi], sgrads)
                     # Steps 7/8: dfx back over the downlink
                     for c, b, dfx in zip(group, batches, dfxs):
                         dfx = self.channel.downlink_grads(c, dfx)
-                        client_params[c] = self._get_client_update(
-                            splits[c])(client_params[c], b, dfx)
+                        with span("s2fl.client_update", cid=c,
+                                  split=splits[c]):
+                            client_params[c] = self._get_client_update(
+                                splits[c])(client_params[c], b, dfx)
 
             # step 8.5: the trained Wc rides back over the collect leg
             # (codec round-trip + exact metering, passthrough on fp32)
@@ -634,7 +659,7 @@ class S2FLEngine:
         if self._fedavg_step is None:
             m = self.model
 
-            def step(p, batch):
+            def fedavg_step(p, batch):
                 (l, met), g = jax.value_and_grad(m.full_loss,
                                                  has_aux=True)(p, batch)
                 new = jax.tree.map(
@@ -642,7 +667,7 @@ class S2FLEngine:
                                    ).astype(w.dtype), p, g)
                 return new, l
 
-            self._fedavg_step = jax.jit(step)
+            self._fedavg_step = jax.jit(fedavg_step)
 
         losses = []
 
@@ -702,6 +727,7 @@ class S2FLEngine:
             treedef, [(b + d.astype(b.dtype)).astype(b.dtype)
                       for b, d in zip(lb, deltas)])
 
+    @traced("s2fl.aggregate")
     def _commit(self, gids):
         """Aggregate the work items whose completion events landed in
         this window (sync: always exactly this round's; semi_async:

@@ -56,21 +56,23 @@ def make_s2fl_loss(cfg, split: int, n_groups: int, dp_axes=None,
     _grad_cast.defvjp(_gc_fwd, _gc_bwd)
 
     def loss_fn(params, batch):
-        feats = model.client_forward(params, batch, split, train=True)
-        h = _grad_cast(feats["h"])
-        h = jnp.take(h, batch["perm"], axis=0)               # all-to-all
-        labels = jnp.take(batch["labels"], batch["perm"], axis=0)
-        tokens = jnp.take(batch["tokens"], batch["perm"], axis=0)
-        B = h.shape[0]
-        gb = B // n_groups
-        hg = h.reshape(n_groups, gb, *h.shape[1:])
-        lg = labels.reshape(n_groups, gb, *labels.shape[1:])
-        tg = tokens.reshape(n_groups, gb, *tokens.shape[1:])
-        # keep the per-group batch dim on the data axes through the
-        # permutation (otherwise SPMD replicates the server half)
-        hg = csts(hg, P(None, dp_axes, *([None] * (h.ndim - 1))))
-        lg = csts(lg, P(None, dp_axes, *([None] * (labels.ndim - 1))))
-        tg = csts(tg, P(None, dp_axes, *([None] * (tokens.ndim - 1))))
+        with jax.named_scope("client_fwd"):
+            feats = model.client_forward(params, batch, split, train=True)
+        with jax.named_scope("regroup"):
+            h = _grad_cast(feats["h"])
+            h = jnp.take(h, batch["perm"], axis=0)           # all-to-all
+            labels = jnp.take(batch["labels"], batch["perm"], axis=0)
+            tokens = jnp.take(batch["tokens"], batch["perm"], axis=0)
+            B = h.shape[0]
+            gb = B // n_groups
+            hg = h.reshape(n_groups, gb, *h.shape[1:])
+            lg = labels.reshape(n_groups, gb, *labels.shape[1:])
+            tg = tokens.reshape(n_groups, gb, *tokens.shape[1:])
+            # keep the per-group batch dim on the data axes through the
+            # permutation (otherwise SPMD replicates the server half)
+            hg = csts(hg, P(None, dp_axes, *([None] * (h.ndim - 1))))
+            lg = csts(lg, P(None, dp_axes, *([None] * (labels.ndim - 1))))
+            tg = csts(tg, P(None, dp_axes, *([None] * (tokens.ndim - 1))))
 
         def group_loss(hh, ll, tt):
             l, _ = model.server_loss(
@@ -78,7 +80,8 @@ def make_s2fl_loss(cfg, split: int, n_groups: int, dp_axes=None,
                 {"tokens": tt, "labels": ll}, split, train=True)
             return l
 
-        losses = jax.vmap(group_loss)(hg, lg, tg)            # G copies
+        with jax.named_scope("server_loss"):
+            losses = jax.vmap(group_loss)(hg, lg, tg)        # G copies
         return losses.mean() * group_members + feats["aux"]
 
     return loss_fn
@@ -89,14 +92,15 @@ def make_s2fl_train_step(cfg, split: int, n_groups: int, lr: float,
     loss_fn = make_s2fl_loss(cfg, split, n_groups, dp_axes=dp_axes,
                              group_members=group_members)
 
-    def step(params, batch):
+    def s2fl_round_step(params, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        params = jax.tree.map(
-            lambda w, g: (w - lr * g.astype(w.dtype)).astype(w.dtype),
-            params, grads)
+        with jax.named_scope("sgd"):
+            params = jax.tree.map(
+                lambda w, g: (w - lr * g.astype(w.dtype)).astype(w.dtype),
+                params, grads)
         return params, loss
 
-    return step
+    return s2fl_round_step
 
 
 def train_step_shardings(cfg, mesh, batch_abstract):

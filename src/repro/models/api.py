@@ -96,23 +96,26 @@ class SplitModel:
         if self.is_cnn:
             h = cnn_mod.cnn_apply_range(self.cfg, params, feats["h"], split,
                                         self.n_units)
-            logits = cnn_mod.cnn_head(self.cfg, params, h)
-            onehot = jax.nn.one_hot(batch["y"], self.cfg.n_classes)
-            ce = -jnp.mean(jnp.sum(onehot * jax.nn.log_softmax(logits), -1))
-            acc = jnp.mean((jnp.argmax(logits, -1) == batch["y"])
-                           .astype(jnp.float32))
+            with jax.named_scope("head"):
+                logits = cnn_mod.cnn_head(self.cfg, params, h)
+                onehot = jax.nn.one_hot(batch["y"], self.cfg.n_classes)
+                ce = -jnp.mean(jnp.sum(onehot * jax.nn.log_softmax(logits),
+                                       -1))
+                acc = jnp.mean((jnp.argmax(logits, -1) == batch["y"])
+                               .astype(jnp.float32))
             return ce + feats["aux"], {"ce": ce, "acc": acc}
         h = feats["h"]
         positions = jnp.arange(h.shape[1], dtype=jnp.int32)
         h, _, aux = tf_mod.apply_blocks(self.cfg, params, h, split,
                                         self.cfg.n_layers, positions,
                                         train=train)
-        logits = tf_mod.apply_head(self.cfg, params, h)
-        P = logits.shape[1] - batch["tokens"].shape[1]
-        if P:
-            logits = logits[:, P:]
         from repro.models.layers import cross_entropy
-        ce = cross_entropy(logits, batch["labels"], self.cfg.vocab_size)
+        with jax.named_scope("head"):
+            logits = tf_mod.apply_head(self.cfg, params, h)
+            P = logits.shape[1] - batch["tokens"].shape[1]
+            if P:
+                logits = logits[:, P:]
+            ce = cross_entropy(logits, batch["labels"], self.cfg.vocab_size)
         loss = ce + aux + feats["aux"]
         return loss, {"ce": ce, "aux": aux + feats["aux"]}
 

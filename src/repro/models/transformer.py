@@ -87,11 +87,15 @@ def _apply_block_kind(cfg, mixer, ffn, bp, shared, h, positions, cache,
 
     if mixer == "shared_attn":
         sp = shared
-        a, cache = attn_mod.attn_apply(cfg, "attn", sp["mixer"],
-                                       rmsnorm(bp["norm1"], h, cfg.norm_eps),
-                                       positions, cache, cache_index)
+        with jax.named_scope("attention"):
+            a, cache = attn_mod.attn_apply(
+                cfg, "attn", sp["mixer"],
+                rmsnorm(bp["norm1"], h, cfg.norm_eps), positions, cache,
+                cache_index)
         h = h + a
-        f = mlp(sp["ffn"], rmsnorm(sp["norm2"], h, cfg.norm_eps), cfg.act)
+        with jax.named_scope("ffn"):
+            f = mlp(sp["ffn"], rmsnorm(sp["norm2"], h, cfg.norm_eps),
+                    cfg.act)
         return h + f, cache, aux
 
     if mixer == "ssm":
@@ -99,17 +103,21 @@ def _apply_block_kind(cfg, mixer, ffn, bp, shared, h, positions, cache,
                                      rmsnorm(bp["norm1"], h, cfg.norm_eps),
                                      cache)
     else:
-        a, cache = attn_mod.attn_apply(cfg, mixer, bp["mixer"],
-                                       rmsnorm(bp["norm1"], h, cfg.norm_eps),
-                                       positions, cache, cache_index)
+        with jax.named_scope("attention"):
+            a, cache = attn_mod.attn_apply(
+                cfg, mixer, bp["mixer"],
+                rmsnorm(bp["norm1"], h, cfg.norm_eps), positions, cache,
+                cache_index)
     h = h + a
 
-    if ffn == "dense":
-        h = h + mlp(bp["ffn"], rmsnorm(bp["norm2"], h, cfg.norm_eps), cfg.act)
-    elif ffn == "moe":
-        f, aux = moe_mod.moe_apply(cfg, bp["ffn"],
-                                   rmsnorm(bp["norm2"], h, cfg.norm_eps))
-        h = h + f
+    with jax.named_scope("ffn"):
+        if ffn == "dense":
+            h = h + mlp(bp["ffn"], rmsnorm(bp["norm2"], h, cfg.norm_eps),
+                        cfg.act)
+        elif ffn == "moe":
+            f, aux = moe_mod.moe_apply(cfg, bp["ffn"],
+                                       rmsnorm(bp["norm2"], h, cfg.norm_eps))
+            h = h + f
     return h, cache, aux
 
 
