@@ -93,7 +93,12 @@ class SlidingSplitScheduler:
             if not known:
                 out[c] = self.plan.smallest()
                 continue
-            out[c] = min(known, key=lambda st: abs(st[1] - median))[0]
+            # distances that agree to 1e-9 of the median are a tie, won by
+            # the first split: a last-ulp difference in an observed time
+            # (the pipelined clock sums its phases) must not flip it
+            gaps = [(s, abs(ts - median)) for s, ts in known]
+            best = min(g for _, g in gaps)
+            out[c] = next(s for s, g in gaps if g <= best + 1e-9 * median)
         return out
 
     def _candidate_times(self, participants) -> dict:

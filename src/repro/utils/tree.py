@@ -1,8 +1,6 @@
-"""Small pytree helpers used by Algorithm-1 aggregation and optimizers."""
+"""Path access into nested param dicts, used by Algorithm-1 aggregation
+and the engine's split views."""
 from __future__ import annotations
-
-import jax
-import jax.numpy as jnp
 
 
 def get_subtree(tree, path):
@@ -26,24 +24,3 @@ def set_subtree(tree, path, value):
         out[head] = set_subtree(tree[head], rest, value)
         return type(tree)(out) if isinstance(tree, tuple) else out
     raise TypeError(type(tree))
-
-
-def tree_add(a, b):
-    return jax.tree.map(jnp.add, a, b)
-
-
-def tree_scale(a, s):
-    return jax.tree.map(lambda x: x * s, a)
-
-
-def tree_weighted_sum(trees, weights):
-    """sum_i w_i * tree_i / sum_i w_i"""
-    total = sum(weights)
-    acc = tree_scale(trees[0], weights[0] / total)
-    for t, w in zip(trees[1:], weights[1:]):
-        acc = tree_add(acc, tree_scale(t, w / total))
-    return acc
-
-
-def tree_zeros_like(t):
-    return jax.tree.map(jnp.zeros_like, t)

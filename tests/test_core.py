@@ -15,6 +15,7 @@ from repro.core.simulation import Device, make_device_grid
 from repro.core.split import SplitPlan, default_plan
 from repro.configs import get_config, make_reduced
 from repro.models import SplitModel
+from repro.utils.tree import get_subtree, set_subtree
 
 KEY = jax.random.PRNGKey(3)
 
@@ -229,6 +230,97 @@ def test_aggregate_mixed_splits(small_model):
     out = aggregate(model, clients, {0: fives, 1: fives})
     b1 = out["blocks"][1]["norm1"]["scale"]
     np.testing.assert_allclose(np.asarray(b1), (5.0 + 2.0) / 2, rtol=1e-6)
+
+
+def _alg1_oracle(model, clients, server_copies):
+    """Algorithm 1 in float64 NumPy, segment by segment; a leaf in no
+    segment keeps clients[0]'s value."""
+    out = jax.tree.map(lambda x: np.asarray(x, np.float64),
+                       clients[0].params)
+    total = sum(c.data_size for c in clients)
+    for name, path in model.segments():
+        acc = None
+        for c in clients:
+            src = (c.params if name in model.client_segments(c.split)
+                   else server_copies[c.group])
+            part = jax.tree.map(
+                lambda x, w=c.data_size: np.asarray(x, np.float64) * w,
+                get_subtree(src, path))
+            acc = part if acc is None else jax.tree.map(np.add, acc, part)
+        out = set_subtree(out, path, jax.tree.map(lambda x: x / total, acc))
+    return out
+
+
+def _assert_matches_oracle(out, want):
+    """rtol 1e-6 against the float64 oracle, where an element that nearly
+    cancels in the sum is held to 1e-6 of its leaf's largest value."""
+    assert jax.tree.structure(out) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a, np.float64), b, rtol=1e-6,
+                                   atol=1e-6 * np.abs(b).max())
+
+
+def _mixed_federation(model, splits, groups, sizes):
+    """Random client params with an extra leaf that lies in no segment,
+    and one random server copy per group."""
+    keys = jax.random.split(KEY, len(splits) + len(set(groups)))
+
+    def params(k, i):
+        return {**model.init(k), "unsegmented": jnp.full((3,), float(i))}
+
+    clients = [ClientState(cid=i, params=params(keys[i], i), split=s,
+                           data_size=d, group=g)
+               for i, (s, g, d) in enumerate(zip(splits, groups, sizes))]
+    copies = {g: params(keys[len(splits) + j], 100 + g)
+              for j, g in enumerate(sorted(set(groups)))}
+    return clients, copies
+
+
+@pytest.mark.parametrize("arch,splits", [
+    ("resnet8", (1, 3, 2, 1, 3)),
+    ("internlm2-1.8b", (1, 2, 2, 1, 1)),
+    ("zamba2-1.2b", (2, 1, 1, 2, 2)),       # shared_attn follows split 2
+])
+def test_aggregate_matches_float64_oracle(arch, splits):
+    """Mixed splits, two groups, unequal |D_i|: every segment equals the
+    float64 Algorithm-1 oracle at rtol 1e-6, and the unsegmented leaf is
+    clients[0]'s."""
+    cfg = get_config(arch)
+    model = SplitModel(cfg if arch == "resnet8" else make_reduced(cfg))
+    clients, copies = _mixed_federation(
+        model, splits, groups=(0, 1, 0, 1, 1),
+        sizes=(37.0, 5.0, 120.0, 64.0, 9.0))
+    out = aggregate(model, clients, copies)
+    want = _alg1_oracle(model, clients, copies)
+    for a in jax.tree.leaves(out):
+        assert a.dtype == jnp.float32
+    _assert_matches_oracle(out, want)
+    np.testing.assert_array_equal(np.asarray(out["unsegmented"]), 0.0)
+
+
+def test_aggregate_traces_once_per_device_count(small_model, monkeypatch):
+    """New data sizes with the same device count reuse the compiled sum:
+    the scales are a traced argument, not constants."""
+    import repro.core.aggregation as agg
+    leaf_sum, traced = agg._leaf_sum, []
+
+    def counting(scales, *xs):          # runs only while jit traces
+        traced.append(len(xs))
+        return leaf_sum(scales, *xs)
+
+    monkeypatch.setattr(agg, "_leaf_sum", counting)
+    n = 7                      # a device count no other test aggregates
+    clients, copies = _mixed_federation(
+        small_model, (1, 2) * 3 + (1,), (0, 1) * 3 + (0,),
+        [float(i + 1) for i in range(n)])
+    aggregate(small_model, clients, copies)
+    first = len(traced)
+    assert first == len(jax.tree.leaves(small_model.abstract()))
+    for c in clients:
+        c.data_size *= 3.0 + c.cid
+    out = aggregate(small_model, clients, copies)
+    assert len(traced) == first
+    _assert_matches_oracle(out, _alg1_oracle(small_model, clients, copies))
 
 
 @given(st.lists(st.floats(0.1, 10.0), min_size=2, max_size=4))

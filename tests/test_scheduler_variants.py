@@ -2,6 +2,10 @@
 including the non-monotone-time regime where median matching loses.
 Rounds are driven through the shared RoundDriver (CallableCost adapts a
 plain t_of(cid, split) table)."""
+import math
+
+import numpy as np
+import pytest
 
 from repro.core.driver import CallableCost, RoundDriver
 from repro.core.scheduler import (FixedSplitScheduler, MinTimeScheduler,
@@ -100,3 +104,22 @@ def test_fixed_scheduler_interface():
     s.observe(5, 2, 1.0)
     s.end_round()
     assert not s.warming_up
+
+
+@pytest.mark.parametrize("ulps", [-2, -1, 0, 1, 2])
+def test_median_tie_ignores_last_ulps(ulps):
+    """With an even count of times the median is the midpoint of the two
+    middle ones; when both are one device's, its two splits tie exactly.
+    The first split wins however the last bits of a time round (the
+    pipelined clock sums its phases and rounds differently)."""
+    plan = SplitPlan(n_units=4, split_points=(1, 2, 3))
+    sched = SlidingSplitScheduler(plan)
+    t2 = 7.0
+    for _ in range(abs(ulps)):
+        t2 = np.nextafter(t2, math.copysign(np.inf, ulps))
+    T = {(0, 1): 5.0, (0, 2): float(t2), (0, 3): 100.0,
+         (1, 1): 0.5, (1, 2): 1.0, (1, 3): 200.0}
+    for (c, s), t in T.items():
+        sched.observe(c, s, t)
+    sched.round = plan.k                    # past the warm-up
+    assert sched.select([0, 1]) == {0: 1, 1: 2}
