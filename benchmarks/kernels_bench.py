@@ -67,17 +67,16 @@ def run_model_kernels():
                   x, dt, A, B, C)
     emit("kern.ssd_scan.2x512x4x64", us_k, f"ref_us={us_r:.0f}")
 
-    from repro.kernels.moe_gmm.kernel import moe_gmm
-    from repro.kernels.moe_gmm.ref import moe_gmm_ref
-    ks = jax.random.split(KEY, 4)
-    xg = jax.random.normal(ks[0], (8, 128, 256)) * 0.5
+    from repro.kernels.moe_gmm.kernel import held_experts_gmm
+    from repro.kernels.moe_gmm.ref import ragged
+    ks = jax.random.split(KEY, 2)
+    xg = jax.random.normal(ks[0], (2048, 256)) * 0.5
     wg = jax.random.normal(ks[1], (8, 256, 512)) * 0.05
-    wu = jax.random.normal(ks[2], (8, 256, 512)) * 0.05
-    wd = jax.random.normal(ks[3], (8, 512, 256)) * 0.05
-    us_k = _bench(jax.jit(lambda *a: moe_gmm(*a, interpret=True)),
-                  xg, wg, wu, wd)
-    us_r = _bench(jax.jit(moe_gmm_ref), xg, wg, wu, wd)
-    emit("kern.moe_gmm.8x128x256x512", us_k, f"ref_us={us_r:.0f}")
+    sizes = jnp.full((8,), 192, jnp.int32)
+    us_k = _bench(jax.jit(lambda *a: held_experts_gmm(*a, interpret=True)),
+                  xg, wg, sizes)
+    us_r = _bench(jax.jit(ragged), xg, wg, sizes)
+    emit("kern.held_experts_gmm.2048x256x512x8", us_k, f"ref_us={us_r:.0f}")
 
 
 def run_comm_kernels():

@@ -15,6 +15,19 @@ def _round_up(x: int, m: int) -> int:
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnRope:
+    """YaRN rotary scaling, with the fields of a published
+    ``rope_scaling`` of type ``yarn`` (``models/layers.rope_freqs``)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Configuration for a decoder-style model (dense / moe / ssm / hybrid /
     vlm / audio backbones)."""
@@ -34,6 +47,7 @@ class ModelConfig:
     ffn_pattern: tuple = ()
     # attention
     rope_theta: float = 10000.0
+    rope_scaling: YarnRope | None = None
     sliding_window: int = 0             # window size for 'swa' blocks
     # MLA (deepseek-style latent attention)
     mla: bool = False
@@ -48,8 +62,9 @@ class ModelConfig:
     top_k: int = 0
     moe_d_ff: int = 0
     router_aux_coef: float = 0.001
-    moe_dispatch_shards: int = 0        # >1: shard-local dispatch (moe.py)
-    moe_dispatch_axes: tuple = ()       # mesh axes of the shard dim
+    norm_topk_prob: bool = True         # renormalise the top-k gate weights
+    held_experts: tuple = ()            # (first, count) of the routed experts
+                                        # this chip holds; () holds all (moe.py)
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
     ssm_head_dim: int = 64

@@ -1,7 +1,7 @@
 """Fused SPMD S²FL round step — the pod-scale form of Algorithm 2.
 
-Mapping (DESIGN.md §2): the global batch dim hosts the x participating
-device cohorts (data-parallel shards). One jitted step performs:
+Mapping: the global batch dim hosts the x participating device cohorts
+(data-parallel shards), as the paper's clients. One jitted step performs:
 
   client-half forward  (batch sharded over `data`)
   balance permutation  (jnp.take over the global batch -> all-to-all; this

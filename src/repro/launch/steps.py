@@ -114,13 +114,6 @@ def build_train_step(cfg, mesh, *, split=None, n_groups: int = DEFAULT_GROUPS,
         repl["scan_layers"] = scan_layers
     if remat_policy is not None:
         repl["remat_policy"] = remat_policy
-    if cfg.n_experts and not cfg.moe_dispatch_shards:
-        axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-        nsh = 1
-        for a in data_axes(mesh):
-            nsh *= axis_sizes[a]
-        repl["moe_dispatch_shards"] = nsh   # shard-local dispatch (moe.py)
-        repl["moe_dispatch_axes"] = tuple(data_axes(mesh))
     if repl:
         cfg = dataclasses.replace(cfg, **repl)
     split = split if split is not None else default_split(cfg)

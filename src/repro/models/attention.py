@@ -20,7 +20,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import apply_rope
+from repro.models.layers import apply_rope, yarn_mscale
 from repro.models.params import ParamDef
 
 NEG_INF = -1e30
@@ -63,18 +63,22 @@ def _pick_q_block(S: int) -> int:
 
 
 def grouped_attention(q, k, v, q_pos, k_pos, *, window: int = 0,
-                      causal: bool = True, impl: str = "xla"):
+                      causal: bool = True, impl: str = "xla",
+                      scale: float | None = None):
     """q: (B,S,H,Dq) k: (B,T,K,Dq) v: (B,T,K,Dv); GQA via H = K*G.
 
     Returns (B,S,H,Dv). Positions are 1-D int32 arrays (right-aligned,
-    no padding semantics — masking is purely positional).
+    no padding semantics — masking is purely positional). ``scale`` of
+    the scores: 1/sqrt(Dq) unless given.
     """
     B, S, H, Dq = q.shape
     T, K = k.shape[1], k.shape[2]
     G = H // K
-    scale = 1.0 / math.sqrt(Dq)
+    scale = 1.0 / math.sqrt(Dq) if scale is None else scale
 
     if impl == "pallas" and S > 1:
+        if scale != 1.0 / math.sqrt(Dq):
+            raise NotImplementedError("the kernel scales by 1/sqrt(Dq)")
         from repro.kernels.flash_attention import ops as fa_ops
         return fa_ops.flash_attention(q, k, v, q_pos, k_pos, window=window,
                                       causal=causal)
@@ -198,13 +202,27 @@ def gqa_apply(cfg, kind, p, x, positions, cache=None, cache_index=None):
 # ---------------------------------------------------------------------------
 # MLA paths
 # ---------------------------------------------------------------------------
+# The published DeepSeek-V2 checkpoints store each head's rope columns of
+# ``wq`` and ``w_kr`` interleaved (pairs (2i, 2i+1)), and their rotary code
+# de-interleaves them before rotating the pairs (i, i + Dr/2) as here: a
+# fixed permutation of those weight columns, not a different function.
+def mla_softmax_scale(cfg) -> float:
+    """1/sqrt(qk head dim), times YaRN's mscale(mscale_all_dim)^2 where
+    the config scales its rope (DeepSeek-V2's ``softmax_scale``)."""
+    scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    s = cfg.rope_scaling
+    if s is not None and s.mscale_all_dim:
+        scale *= yarn_mscale(s.factor, s.mscale_all_dim) ** 2
+    return scale
+
+
 def _mla_latent(cfg, p, x, positions):
     from repro.models.layers import rmsnorm
     latent = x @ p["w_dkv"].astype(x.dtype)
     latent = rmsnorm({"scale": p["latent_norm"]}, latent, cfg.norm_eps)
     k_rope = x @ p["w_kr"].astype(x.dtype)               # (B,S,Dr)
     k_rope = apply_rope(k_rope[:, :, None, :], positions,
-                        cfg.rope_theta)[:, :, 0, :]
+                        cfg.rope_theta, cfg.rope_scaling)[:, :, 0, :]
     return latent, k_rope
 
 
@@ -214,7 +232,8 @@ def mla_apply(cfg, p, x, positions, cache=None, cache_index=None):
     Dn, Dr, Dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
     q_nope, q_rope = q[..., :Dn], q[..., Dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
+    scale = mla_softmax_scale(cfg)
 
     if cache is None or S > 1:                          # train / prefill
         latent, k_rope = _mla_latent(cfg, p, x, positions)
@@ -225,7 +244,8 @@ def mla_apply(cfg, p, x, positions, cache=None, cache_index=None):
                                       (B, S, H, Dr))], axis=-1)
         qq = jnp.concatenate([q_nope, q_rope], axis=-1)
         out = grouped_attention(qq, k, v, positions, positions,
-                                causal=True, impl=cfg.attn_impl)
+                                causal=True, impl=cfg.attn_impl,
+                                scale=scale)
         if cache is not None:
             cache = {
                 "latent": jax.lax.dynamic_update_slice(
@@ -243,7 +263,6 @@ def mla_apply(cfg, p, x, positions, cache=None, cache_index=None):
                 cache["k_rope"], k_rope, (0, idx, 0)),
         }
         T = cache["latent"].shape[1]
-        scale = 1.0 / math.sqrt(Dn + Dr)
         # absorb w_uk into the query: (B,1,H,R)
         q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, p["w_uk"].astype(x.dtype))
         s = (jnp.einsum("bshr,btr->bhst", q_lat.astype(jnp.float32),

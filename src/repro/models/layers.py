@@ -1,6 +1,8 @@
 """Basic layers: RMSNorm, MLPs, embeddings, rotary embeddings."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -66,19 +68,57 @@ def head_defs(d_model: int, vocab_padded: int):
 # ---------------------------------------------------------------------------
 # Rotary embeddings
 # ---------------------------------------------------------------------------
-def rope_freqs(head_dim: int, theta: float):
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                            / head_dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor (DeepSeek-V2's
+    ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., S, H, D) with positions (..., S) or (S,)."""
+def _yarn_ramp(scaling, head_dim: int, theta: float):
+    """YaRN's per-frequency interpolation weight: 0 keeps a pair's
+    frequency, 1 divides it by the factor, linear between the pairs that
+    turn ``beta_fast`` and ``beta_slow`` times over the original
+    context."""
+    def dim_of(turns):
+        return head_dim * math.log(
+            scaling.original_max_position_embeddings /
+            (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(scaling.beta_fast)), 0)
+    high = min(math.ceil(dim_of(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    i = jnp.arange(head_dim // 2, dtype=jnp.float32)
+    return jnp.clip((i - low) / (high - low), 0.0, 1.0)
+
+
+def rope_freqs(head_dim: int, theta: float, scaling=None):
+    """Rotary frequencies; with ``scaling`` (``configs.base.YarnRope``)
+    the YaRN frequencies of DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``,
+    which blends each frequency with itself over the factor."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                             / head_dim))
+    if scaling is None:
+        return freqs
+    ramp = _yarn_ramp(scaling, head_dim, theta)
+    return freqs / scaling.factor * ramp + freqs * (1.0 - ramp)
+
+
+def apply_rope(x, positions, theta: float, scaling=None):
+    """x: (..., S, H, D) with positions (..., S) or (S,). The pairs are
+    (i, i + D/2). ``scaling``: YaRN (``rope_freqs``), whose cos and sin
+    carry the ratio of the two mscales."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                       # (d/2,)
+    freqs = rope_freqs(d, theta, scaling)              # (d/2,)
     ang = positions.astype(jnp.float32)[..., None] * freqs   # (..., S, d/2)
     # broadcast over head axis: (..., S, 1, d/2)
     ang = ang[..., None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None:
+        m = yarn_mscale(scaling.factor, scaling.mscale) / yarn_mscale(
+            scaling.factor, scaling.mscale_all_dim)
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

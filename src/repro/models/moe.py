@@ -1,32 +1,56 @@
-"""Mixture-of-Experts FFN: top-k routing, shared experts, capacity-based
-scatter dispatch (static shapes, SPMD-friendly — the expert axis shards over
-the `model` mesh axis, so the dispatch/combine gathers lower to all-to-all
-style collectives).
+"""Mixture-of-Experts FFN for the routed experts one chip holds: top-k
+routing over every expert, dropless dispatch to the held experts through
+the grouped-matmul kernel, and shared experts.
 
-Dispatch avoids the classic (T, E, C) one-hot (infeasible at pod batch
-sizes). Positions-in-expert come from SORT-BASED ranking: a stable argsort
-of the (T*k,) expert assignments, ranks within runs via searchsorted, then
-inverse-permute. The earlier (T*k, E) one-hot + cumsum formulation costs
-1.7e15 flops/chip in compiled HLO at kimi-k2 train shapes (XLA's cumsum
-lowering), vs 3.5e8 for the sort — see EXPERIMENTS.md §Perf-moe-dispatch.
-Dispatch/combine are scatter/gather at (expert, slot); the compute-bound
-expert matmul can route through the ``repro.kernels.moe_gmm`` Pallas
-kernel (``cfg.attn_impl == 'pallas'``).
+Expert parallelism divides each layer's routed experts over chips; this
+layer is one chip's share. ``cfg.held_experts`` = (first, count) names the
+contiguous range of experts held here (empty: all of them, the whole
+layer). The router keeps its full width: every token is scored against
+every expert, and its top-k choices are made over all of them. The layer
+computes only the held experts' part of the result, for the token-expert
+pairs routed to them, plus the shared experts, which every chip holds.
+What the other chips' experts would add is not computed here, and no code
+stands in for them or for the all-to-all that would carry their rows: the
+layer's output is this share, and the shares of all chips, with the shared
+experts counted once, add up to the whole layer's output.
+
+Dispatch is dropless under any imbalance. The (token, choice) pairs are
+sorted by held expert, the pairs of other experts after them; the sorted
+buffer has a row for every pair, so no expert can overflow. Only the held
+rows are gathered (the rest are filled, not read) and only they are
+computed: the kernel's work follows the group sizes. Both the dispatch
+gather and the combine gather have gathers as their transposes (each row
+is one pair), so neither pass scatters.
+
+The balance loss is DeepSeek's sequence-level one (DeepSeek-V2, arXiv
+2405.04434, Sec. 2.2.3): per sequence, alpha * sum_e f_e P_e, with f_e the
+expert's share of the sequence's k choices times E and P_e its mean
+routing score, averaged over the sequences; it is computed from the full
+routing, which each chip has whole.
 """
 from __future__ import annotations
 
-import math
+import functools
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.moe_gmm import grouped_matmul
+from repro.models.layers import mlp
 from repro.models.params import ParamDef
 
 
+def held_range(cfg):
+    """(first, count) of the routed experts this layer holds."""
+    return tuple(cfg.held_experts) or (0, cfg.n_experts)
+
+
 def moe_defs(cfg):
-    d, E, F = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
+    d, F = cfg.d_model, cfg.moe_d_ff
+    E = held_range(cfg)[1]
     defs = {
-        "router": ParamDef((d, E), ("embed", "experts"), dtype="float32"),
+        "router": ParamDef((d, cfg.n_experts), ("embed", "experts"),
+                           dtype="float32"),
         "w_gate": ParamDef((E, d, F), ("experts", "embed", "expert_ff")),
         "w_up": ParamDef((E, d, F), ("experts", "embed", "expert_ff")),
         "w_down": ParamDef((E, F, d), ("experts", "expert_ff", "embed")),
@@ -41,96 +65,117 @@ def moe_defs(cfg):
     return defs
 
 
-def _expert_ffn(p, x, act):
-    """x: (E, C, d) -> (E, C, d), batched over experts."""
-    actf = jax.nn.silu if act == "silu" else jax.nn.gelu
-    g = actf(jnp.einsum("ecd,edf->ecf", x, p["w_gate"].astype(x.dtype)))
-    u = jnp.einsum("ecd,edf->ecf", x, p["w_up"].astype(x.dtype))
-    return jnp.einsum("ecf,efd->ecd", g * u, p["w_down"].astype(x.dtype))
+def route(cfg, router, x):
+    """x (B, S, d) -> softmax scores (B, S, E) in f32 and the top-k
+    (weights, experts), each (B, S, k). The logits are computed in f32 at
+    full precision, as the published gate computes them."""
+    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32), router,
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.softmax(logits, axis=-1)
+    weights, experts = jax.lax.top_k(scores, cfg.top_k)
+    if cfg.norm_topk_prob:
+        weights = weights / jnp.sum(weights, -1, keepdims=True)
+    return scores, weights, experts
 
 
-def _dispatch_combine(cfg, p, xt, *, capacity_factor: float):
-    """Dispatch -> expert FFN -> combine for a token slab xt (T, d).
-    Positions are first-come-first-served in token order (sort-based)."""
-    T, d = xt.shape
-    E, k = cfg.n_experts, cfg.top_k
-
-    logits = xt.astype(jnp.float32) @ p["router"]        # (T, E) f32
-    gates = jax.nn.softmax(logits, axis=-1)
-    topw, topi = jax.lax.top_k(gates, k)                 # (T, k)
-    topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
-
-    # load-balance aux loss (Switch-style): E * sum_e f_e * P_e
-    density = jnp.zeros((E,), jnp.float32).at[topi.reshape(-1)].add(
-        1.0) / (T * k)
-    aux = E * jnp.sum(density * gates.mean(0)) * cfg.router_aux_coef
-
-    C = int(capacity_factor * k * T / E)
-    C = max(8, math.ceil(C / 8) * 8)
-
-    flat_e = topi.reshape(-1)                            # (T*k,)
-    N = flat_e.shape[0]
-    order = jnp.argsort(flat_e, stable=True)
-    sorted_e = flat_e[order]
-    starts = jnp.searchsorted(sorted_e, jnp.arange(E))   # run starts
-    rank_sorted = jnp.arange(N) - starts[sorted_e]
-    flat_pos = jnp.zeros((N,), jnp.int32).at[order].set(rank_sorted)
-    keep = flat_pos < C                                  # overflow dropped
-
-    safe_pos = jnp.where(keep, flat_pos, C - 1)
-    x_rep = jnp.repeat(xt, k, axis=0)                    # (T*k, d)
-    exp_in = jnp.zeros((E, C, d), xt.dtype).at[flat_e, safe_pos].add(
-        jnp.where(keep[:, None], x_rep, 0).astype(xt.dtype))
-
-    if cfg.attn_impl == "pallas":
-        from repro.kernels.moe_gmm import ops as gmm_ops
-        exp_out = gmm_ops.expert_ffn(p, exp_in, cfg.act)
-    else:
-        exp_out = _expert_ffn(p, exp_in, cfg.act)
-
-    gathered = exp_out[flat_e, safe_pos]                 # (T*k, d)
-    gathered = jnp.where(keep[:, None], gathered, 0)
-    w = topw.reshape(-1).astype(xt.dtype)
-    out = (gathered * w[:, None]).reshape(T, k, d).sum(axis=1)
-    return out, aux
+def sequence_balance_loss(cfg, scores, experts):
+    """DeepSeek's sequence-level balance loss from scores (B, S, E) and
+    the chosen experts (B, S, k)."""
+    _, S, E = scores.shape
+    counts = jnp.sum(jax.nn.one_hot(experts, E, dtype=jnp.float32),
+                     axis=(1, 2))                            # (B, E)
+    f = counts * E / (S * cfg.top_k)
+    return cfg.router_aux_coef * jnp.mean(
+        jnp.sum(f * jnp.mean(scores, axis=1), -1))
 
 
-def moe_apply(cfg, p, x, *, capacity_factor: float = 1.25):
-    """x: (B,S,d). Returns (out, aux_loss).
+def _take(x, idx):
+    """Rows ``x[idx]``; an index past the end gives a zero row, read from
+    nowhere."""
+    return jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
 
-    When ``cfg.moe_dispatch_shards > 1`` (set by the pod-scale launchers),
-    tokens are bucketed PER DATA SHARD: the batch is viewed as
-    (shards, T/shards, d) — physically sharded over `data` — and ranking/
-    scatter/gather are vmapped over the shard dim, so the capacity buffer
-    is (shards, E, C/shards, d) with every scatter local to its shard and
-    only the expert matmul crossing the expert-parallel axis. The global
-    single-bucket form replicates the (E, C, d) buffer across the data
-    axis and all-reduces it per layer — measured 2.2e11 collective
-    B/chip/layer at kimi-k2 train shapes (EXPERIMENTS.md §Perf-kimi).
-    Capacity becomes per-shard (drop decisions local), matching practical
-    expert-parallel systems.
-    """
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(xt, pair_of_row, row_of_pair, k):
+    """The sorted buffer: row i is token ``pair_of_row[i] // k`` (zero past
+    the held rows)."""
+    return _take(xt, pair_of_row // k)
+
+
+def _dispatch_fwd(xt, pair_of_row, row_of_pair, k):
+    return _dispatch(xt, pair_of_row, row_of_pair, k), (pair_of_row,
+                                                        row_of_pair)
+
+
+def _dispatch_bwd(k, res, g):
+    _, row_of_pair = res
+    per_pair = _take(g, row_of_pair).astype(jnp.float32)
+    dx = per_pair.reshape(-1, k, g.shape[-1]).sum(1).astype(g.dtype)
+    return dx, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _unsort(y, row_of_pair, pair_of_row):
+    """Each pair's row of the sorted buffer, in pair order (zero for the
+    pairs of experts held elsewhere)."""
+    return _take(y, row_of_pair)
+
+
+def _unsort_fwd(y, row_of_pair, pair_of_row):
+    return _unsort(y, row_of_pair, pair_of_row), pair_of_row
+
+
+def _unsort_bwd(pair_of_row, g):
+    return _take(g, pair_of_row), None, None
+
+
+_unsort.defvjp(_unsort_fwd, _unsort_bwd)
+
+
+def moe_apply(cfg, p, x):
+    """x (B, S, d) -> (this chip's share of the layer's output (B, S, d),
+    the balance loss)."""
     B, S, d = x.shape
-    T = B * S
-    shards = getattr(cfg, "moe_dispatch_shards", 0) or 1
-    if shards > 1 and B % shards == 0:
-        from jax.sharding import PartitionSpec as P
-        axes = getattr(cfg, "moe_dispatch_axes", ()) or None
-        cst = (lambda v, s: jax.lax.with_sharding_constraint(v, s)) \
-            if axes else (lambda v, s: v)
-        xs = x.reshape(shards, T // shards, d)
-        xs = cst(xs, P(axes, None, None))
-        out, aux = jax.vmap(
-            lambda xt: _dispatch_combine(cfg, p, xt,
-                                         capacity_factor=capacity_factor)
-        )(xs)
-        out = cst(out, P(axes, None, None)).reshape(T, d)
-        aux = aux.mean()
-    else:
-        out, aux = _dispatch_combine(cfg, p, x.reshape(T, d),
-                                     capacity_factor=capacity_factor)
+    T, k = B * S, cfg.top_k
+    first, held = held_range(cfg)
+    actf = jax.nn.silu if cfg.act == "silu" else jax.nn.gelu
+    xt = x.reshape(T, d)
+
+    with jax.named_scope("route"):
+        scores, weights, experts = route(cfg, p["router"], x)
+        aux = sequence_balance_loss(cfg, scores, experts)
+        local = experts.reshape(-1) - first                   # (T*k,)
+        is_held = (local >= 0) & (local < held)
+        key = jnp.where(is_held, local, held)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        group_sizes = jnp.bincount(key, length=held + 1)[:held].astype(
+            jnp.int32)
+        n_rows = jnp.sum(group_sizes)
+        valid = jnp.arange(T * k) < n_rows                    # held rows
+        none = T * k                                          # past the end
+        pair_of_row = jnp.where(valid, order, none)
+        row_of_pair = jnp.full((T * k,), none, jnp.int32).at[order].set(
+            jnp.where(valid, jnp.arange(T * k, dtype=jnp.int32), none))
+        rows = _dispatch(xt, pair_of_row, row_of_pair, k)
+
+    with jax.named_scope("experts"):
+        def gmm(a, name):
+            return grouped_matmul(a, p[name].astype(x.dtype), group_sizes)
+
+        h = actf(gmm(rows, "w_gate")) * gmm(rows, "w_up")
+        # rows past the held ones are undefined out of the kernel
+        h = jnp.where(valid[:, None], h, 0)
+        y = gmm(h, "w_down")
+
+    with jax.named_scope("combine"):
+        per_pair = _unsort(y, row_of_pair, pair_of_row).reshape(T, k, d)
+        out = jnp.sum(per_pair.astype(jnp.float32) *
+                      weights.reshape(T, k, 1), axis=1)
 
     if cfg.n_shared_experts:
-        from repro.models.layers import mlp
-        out = out + mlp(p["shared"], x.reshape(T, d), cfg.act)
-    return out.reshape(B, S, d), aux
+        with jax.named_scope("shared_experts"):
+            out = out + mlp(p["shared"], xt, cfg.act)
+    return out.astype(x.dtype).reshape(B, S, d), aux

@@ -41,6 +41,7 @@ def _compile(one_chip, fn, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 def _vgg_cut_values():
@@ -96,16 +97,24 @@ def test_flash_attention_compiles_at_internlm2(one_chip):
              ((1, S, K, Dh), jnp.bfloat16))
 
 
-def test_moe_gmm_compiles_at_deepseek_v2_lite(one_chip):
-    from repro.kernels.moe_gmm import expert_ffn
+def test_held_experts_gmm_compiles_at_deepseek_v2_lite(one_chip):
+    """Forward and both gradients of the held experts' grouped matmul at
+    the DeepSeek-V2-Lite cell's widths: 8 held experts, d 2,048, F 1,408,
+    a buffer of 16,384 x 6 rows."""
+    from repro.kernels.moe_gmm import grouped_matmul
     cfg = get_config("deepseek-v2-lite-16b")
-    E, d, F = cfg.n_experts, cfg.d_model, cfg.moe_d_ff
-    C = int(1.25 * cfg.top_k * 4096 / E)      # moe.py capacity, 4k tokens
-    _compile(one_chip,
-             lambda x, wg, wu, wd: expert_ffn(
-                 {"w_gate": wg, "w_up": wu, "w_down": wd}, x, cfg.act),
-             ((E, C, d), jnp.bfloat16), ((E, d, F), jnp.bfloat16),
-             ((E, d, F), jnp.bfloat16), ((E, F, d), jnp.bfloat16))
+    E, d, F, M = 8, cfg.d_model, cfg.moe_d_ff, 16384 * cfg.top_k
+
+    def fwd_bwd(x, wg, wd, sizes):
+        def f(x, wg, wd):
+            h = grouped_matmul(x, wg, sizes)
+            return jnp.sum(grouped_matmul(h, wd, sizes).astype(jnp.float32))
+        return jax.grad(f, (0, 1, 2))(x, wg, wd)
+
+    text = _compile(one_chip, fwd_bwd, ((M, d), jnp.bfloat16),
+                    ((E, d, F), jnp.bfloat16), ((E, F, d), jnp.bfloat16),
+                    ((E,), jnp.int32))
+    assert "%held_experts_gmm" in text and "%held_experts_tgmm" in text
 
 
 def test_ssd_scan_compiles_at_zamba2(one_chip):

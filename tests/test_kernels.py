@@ -7,8 +7,8 @@ import pytest
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.moe_gmm.kernel import moe_gmm
-from repro.kernels.moe_gmm.ref import moe_gmm_ref
+from repro.kernels.moe_gmm import (grouped_matmul, held_experts_gmm,
+                                   held_experts_tgmm)
 from repro.kernels.ssd_scan.kernel import ssd_scan_pallas
 from repro.models.ssm import ssd_decode_step, ssd_scan_ref
 
@@ -113,26 +113,83 @@ def test_ssd_chunked_matches_sequential_recurrence():
 
 
 # ---------------------------------------------------------------------------
-# moe gmm
+# held-expert grouped matmuls (moe gmm)
 # ---------------------------------------------------------------------------
 GMM_CASES = [
-    (4, 64, 128, 256, "silu", jnp.float32),
-    (2, 128, 64, 512, "gelu", jnp.float32),
-    (8, 32, 256, 128, "silu", jnp.float32),
-    (2, 64, 128, 256, "silu", jnp.bfloat16),
-    (3, 40, 96, 192, "gelu", jnp.float32),   # non-128 shapes
+    # (m, k, n, group sizes, dtype): the groups need not fill the rows
+    (256, 256, 384, (40, 0, 100, 57), jnp.float32),     # an empty group
+    (128, 128, 256, (0, 0, 128), jnp.float32),           # one full group
+    (512, 256, 128, (300, 212), jnp.bfloat16),
+    (64, 96, 80, (3, 5, 0, 9, 20), jnp.float32),         # non-128 widths
+    (256, 128, 128, (0, 0, 0), jnp.float32),             # nothing routed
 ]
 
 
-@pytest.mark.parametrize("E,C,d,F,act,dtype", GMM_CASES)
-def test_moe_gmm_sweep(E, C, d, F, act, dtype):
-    ks = jax.random.split(KEY, 4)
-    x = (jax.random.normal(ks[0], (E, C, d)) * 0.5).astype(dtype)
-    wg = (jax.random.normal(ks[1], (E, d, F)) * 0.05).astype(dtype)
-    wu = (jax.random.normal(ks[2], (E, d, F)) * 0.05).astype(dtype)
-    wd = (jax.random.normal(ks[3], (E, F, d)) * 0.05).astype(dtype)
-    y1 = moe_gmm(x, wg, wu, wd, act=act, interpret=True)
-    y2 = moe_gmm_ref(x, wg, wu, wd, act=act)
-    atol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
-    np.testing.assert_allclose(np.asarray(y1, np.float32),
-                               np.asarray(y2, np.float32), atol=atol)
+def _gmm_inputs(m, k, n, sizes, dtype):
+    ks = jax.random.split(KEY, 3)
+    g = len(sizes)
+    return ((jax.random.normal(ks[0], (m, k)) * 0.5).astype(dtype),
+            (jax.random.normal(ks[1], (g, k, n)) * 0.1).astype(dtype),
+            (jax.random.normal(ks[2], (m, n))).astype(dtype),
+            jnp.asarray(sizes, jnp.int32))
+
+
+@pytest.mark.parametrize("m,k,n,sizes,dtype", GMM_CASES)
+def test_held_experts_gmm_and_gradients(m, k, n, sizes, dtype):
+    """Forward, input gradient (transposed weights) and weight gradient of
+    the Pallas kernels, interpreted, against ``jax.lax.ragged_dot`` and its
+    VJP, on the rows the groups hold."""
+    x, w, dy, gs = _gmm_inputs(m, k, n, sizes, dtype)
+    rows = np.arange(m) < sum(sizes)
+    tol = dict(atol=5e-2, rtol=2e-2) if dtype == jnp.bfloat16 else \
+        dict(atol=1e-4, rtol=1e-4)
+    f32 = lambda a: np.asarray(a, np.float32)
+    y = held_experts_gmm(x, w, gs, interpret=True)
+    y_ref, vjp = jax.vjp(
+        lambda a, b: jax.lax.ragged_dot(a, b, gs,
+                                        preferred_element_type=jnp.float32
+                                        ).astype(dtype), x, w)
+    dx_ref, dw_ref = vjp(dy)
+    np.testing.assert_allclose(f32(y)[rows], f32(y_ref)[rows], **tol)
+    dx = held_experts_gmm(dy, w, gs, transpose_rhs=True, interpret=True)
+    np.testing.assert_allclose(f32(dx)[rows], f32(dx_ref)[rows], **tol)
+    dw = held_experts_tgmm(x, dy, gs, interpret=True)
+    np.testing.assert_allclose(f32(dw), f32(dw_ref), **tol)
+
+
+def test_held_experts_gmm_under_vmap():
+    """Each batch element with its own group sizes, interpreted."""
+    x, w, dy, gs = _gmm_inputs(256, 128, 256, (40, 0, 100, 57), jnp.float32)
+    xs = jnp.stack([x, 2 * x])
+    dys = jnp.stack([dy, -dy])
+    gss = jnp.stack([gs, jnp.asarray([0, 128, 0, 64], jnp.int32)])
+    ys = jax.vmap(lambda a, s: held_experts_gmm(a, w, s, interpret=True))(
+        xs, gss)
+    dws = jax.vmap(lambda a, b, s: held_experts_tgmm(a, b, s,
+                                                     interpret=True))(
+        xs, dys, gss)
+    for i in range(2):
+        rows = np.arange(256) < int(gss[i].sum())
+        y_ref, vjp = jax.vjp(lambda b: jax.lax.ragged_dot(xs[i], b, gss[i]),
+                             w)
+        np.testing.assert_allclose(np.asarray(ys[i])[rows],
+                                   np.asarray(y_ref)[rows], atol=1e-4)
+        np.testing.assert_allclose(np.asarray(dws[i]),
+                                   np.asarray(vjp(dys[i])[0]), atol=1e-4)
+
+
+def test_grouped_matmul_gradients_on_cpu():
+    """The differentiable op (the oracle on the CPU), under ``vmap``,
+    against autodiff of ``jax.lax.ragged_dot`` on the held rows."""
+    x, w, dy, gs = _gmm_inputs(128, 128, 256, (30, 0, 50, 20), jnp.float32)
+    rows = jnp.arange(128)[:, None] < 100
+
+    def loss(f):
+        return lambda a, b: jnp.sum(jnp.where(rows, f(a, b, gs), 0) * dy)
+
+    got = jax.vmap(jax.grad(loss(grouped_matmul), (0, 1)),
+                   in_axes=(0, None))(jnp.stack([x, x]), w)
+    want = jax.grad(loss(jax.lax.ragged_dot), (0, 1))(x, w)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a[0]), np.asarray(b),
+                                   atol=1e-4)
